@@ -1,9 +1,10 @@
-"""Kernel micro-benchmarks: Pallas (interpret) vs jnp oracle.
+"""Kernel micro-benchmarks: Pallas kernels vs jnp oracle.
 
-On this CPU container interpret-mode timings measure Python emulation, NOT
-TPU performance — the numbers exist to (a) prove the kernels run, and
-(b) regression-track the oracle path.  TPU-side projections come from the
-roofline analysis (see EXPERIMENTS.md §Roofline)."""
+The kernels run with their default ``interpret=None``: compiled on a TPU,
+in Pallas interpret mode everywhere else.  Off the TPU the timings measure
+Python emulation, NOT TPU performance — the numbers exist to (a) prove the
+kernels run, and (b) regression-track the oracle path.  The record names
+(``*.pallas_interp``) are stable keys of the committed baselines."""
 from __future__ import annotations
 
 import numpy as np
@@ -33,7 +34,7 @@ def run(quick: bool = False) -> dict:
     p = make_params(delta_r1=1.0, n_r1=4, n_r2=4, m=3, signed=True)
 
     x = jnp.asarray(rng.normal(0, 30, (256, 256)).astype(np.float32))
-    us = timeit(lambda v: trq_quant_pallas(v, p, interpret=True), x,
+    us = timeit(lambda v: trq_quant_pallas(v, p), x,
                 iters=3 if quick else 5)
     us_ref = timeit(lambda v: trq_quant(v, p), x, iters=3 if quick else 5)
     rec("kernel.trq_quant.pallas_interp", us, "shape=256x256")
@@ -41,8 +42,7 @@ def run(quick: bool = False) -> dict:
 
     a = jnp.asarray(rng.normal(0, 1, (128, 512)).astype(np.float32))
     w = jnp.asarray(rng.normal(0, 1, (512, 128)).astype(np.float32))
-    us = timeit(lambda aa, ww: trq_group_mvm_pallas(aa, ww, p, 0.05, 1.0,
-                                                    interpret=True),
+    us = timeit(lambda aa, ww: trq_group_mvm_pallas(aa, ww, p, 0.05, 1.0),
                 a, w, iters=2 if quick else 4)
     us_ref = timeit(lambda aa, ww: fake_quant_mvm(aa, ww, p, 0.05, 1.0),
                     a, w, iters=2 if quick else 4)
@@ -56,22 +56,20 @@ def run(quick: bool = False) -> dict:
     wd = jnp.asarray(rng.normal(0, 1, (512, 128)).astype(np.float32))
     for m in (1, 8, 16):
         ad = jnp.asarray(rng.normal(0, 1, (m, 512)).astype(np.float32))
-        us = timeit(lambda a_, w_: trq_group_mvm_pallas(a_, w_, p, 0.05, 1.0,
-                                                        interpret=True),
+        us = timeit(lambda a_, w_: trq_group_mvm_pallas(a_, w_, p, 0.05, 1.0),
                     ad, wd, iters=3 if quick else 5)
         rec(f"kernel.trq_group_mvm.decode_m{m}", us, f"m{m}.k512.n128.auto")
         if m == 8:
             us = timeit(
                 lambda a_, w_: trq_group_mvm_pallas(a_, w_, p, 0.05, 1.0,
-                                                    block_m=128,
-                                                    interpret=True),
+                                                    block_m=128),
                 ad, wd, iters=3 if quick else 5)
             rec("kernel.trq_group_mvm.decode_m8_pad128", us,
                 "m8.k512.n128.block_m128")
 
     ai = jnp.asarray(rng.integers(0, 256, (16, 128)).astype(np.int32))
     wi = jnp.asarray(rng.integers(-128, 128, (128, 16)).astype(np.int32))
-    us = timeit(lambda aa, ww: xbar_mvm_pallas(aa, ww, p, interpret=True)[0],
+    us = timeit(lambda aa, ww: xbar_mvm_pallas(aa, ww, p)[0],
                 ai, wi, iters=2 if quick else 3)
     us_ref = timeit(lambda aa, ww: bit_exact_mvm(aa, ww, p), ai, wi,
                     iters=2 if quick else 3)

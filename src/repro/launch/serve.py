@@ -27,11 +27,69 @@ import numpy as np
 
 from repro import runtime
 from repro.dist.sharding import use_mesh
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models.registry import ARCHS, build_model, get_config
 from repro.pim import list_backends
 from repro.serve.engine import ServeEngine
 from repro.telemetry.serve_report import format_energy_report, serve_report
+
+
+def build_engine(cfg, *, max_batch: int = 4, max_len: int = 256,
+                 seed: int = 0, quant_state=None, plan: bool = True,
+                 backend=None, paged: bool = True, block_size: int = 16,
+                 prefix_reuse: bool = True, num_blocks=None) -> ServeEngine:
+    """Init ``cfg``'s parameters from ``seed``, compile the Runtime (which
+    programs the crossbar plan) and wrap it in a ServeEngine.  Call under
+    ``use_mesh``; the Runtime takes the active mesh."""
+    init_fn, apply_fn, cache_fn = build_model(cfg)
+    print(f"arch={cfg.name} pim={cfg.pim_backend} layers={cfg.n_layers} "
+          f"max_batch={max_batch} max_len={max_len} "
+          f"paged={paged} block_size={block_size} "
+          f"prefix_reuse={prefix_reuse}")
+
+    def extra_inputs(b, s):
+        out = {}
+        if (cfg.frontend in ("patch", "frames") or cfg.encoder_layers > 0) \
+                and s > 1:
+            out["embeds"] = jnp.zeros((b, 8, cfg.d_model), jnp.float32)
+        return out
+
+    params = init_fn(jax.random.PRNGKey(seed))
+    # one explicit execution context: mesh/backend/registers/plan are
+    # resolved + programmed here, then the engine is a thin client.
+    # With a backend override, plan programming is deferred to the
+    # with_overrides arm so the crossbars are programmed exactly once.
+    rt = runtime.compile(cfg, params, quant_state=quant_state,
+                         plan=plan if not backend else None,
+                         fns=(init_fn, apply_fn, cache_fn))
+    del params
+    if backend:
+        rt = rt.with_overrides(backend=backend, plan=plan)
+        print(f"backend override: serving on {rt.backend!r}")
+    engine = ServeEngine(rt, max_batch=max_batch, max_len=max_len,
+                         extra_inputs=extra_inputs, paged=paged,
+                         block_size=block_size, prefix_reuse=prefix_reuse,
+                         num_blocks=num_blocks)
+    if engine.plan is not None:
+        print(f"programmed {len(engine.plan)} crossbar layer plans "
+              f"({rt.backend})")
+    return engine
+
+
+def submit_random(engine: ServeEngine, *, requests: int, prompt_len: int,
+                  max_new: int, shared_prefix: int = 0,
+                  temperature: float = 0.0, seed: int = 0) -> None:
+    """Queue ``requests`` prompts of ``prompt_len`` tokens drawn from
+    ``seed``, each behind the same ``shared_prefix``-token system prompt."""
+    vocab = engine.cfg.vocab_size
+    rng = np.random.default_rng(seed)
+    prefix = rng.integers(0, vocab, shared_prefix) if shared_prefix else None
+    for _ in range(requests):
+        tail = rng.integers(0, vocab, prompt_len)
+        prompt = tail if prefix is None else np.concatenate([prefix, tail])
+        engine.submit(prompt, max_new_tokens=max_new,
+                      temperature=temperature)
 
 
 def main(argv=None):
@@ -79,6 +137,7 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     cfg = get_config(args.arch, smoke=args.smoke).replace(
         pim_backend=args.pim, param_dtype="bfloat16", remat="none")
     qs = None
@@ -86,51 +145,18 @@ def main(argv=None):
         from repro.core.quant_state import load_quant_state
         qs = load_quant_state(args.quant_state)
         print(f"loaded {len(qs)} per-layer SAR register rules")
-    mesh = make_host_mesh()
-    init_fn, apply_fn, cache_fn = build_model(cfg)
-    rng = np.random.default_rng(args.seed)
-    print(f"arch={cfg.name} pim={cfg.pim_backend} "
-          f"max_batch={args.max_batch} max_len={args.max_len} "
-          f"paged={args.paged} block_size={args.block_size} "
-          f"prefix_reuse={args.prefix_reuse}")
-
-    def extra_inputs(b, s):
-        out = {}
-        if (cfg.frontend in ("patch", "frames") or cfg.encoder_layers > 0) \
-                and s > 1:
-            out["embeds"] = jnp.zeros((b, 8, cfg.d_model), jnp.float32)
-        return out
-
-    prefix = rng.integers(0, cfg.vocab_size, args.shared_prefix) \
-        if args.shared_prefix else None
-
-    with use_mesh(mesh):
-        params = init_fn(jax.random.PRNGKey(args.seed))
-        # one explicit execution context: mesh/backend/registers/plan are
-        # resolved + programmed here, then the engine is a thin client.
-        # With a --backend override, plan programming is deferred to the
-        # with_overrides arm so the crossbars are programmed exactly once.
-        rt = runtime.compile(cfg, params, quant_state=qs,
-                             plan=args.plan if not args.backend else None,
-                             fns=(init_fn, apply_fn, cache_fn))
-        if args.backend:
-            rt = rt.with_overrides(backend=args.backend, plan=args.plan)
-            print(f"backend override: serving on {rt.backend!r}")
-        engine = ServeEngine(rt,
-                             max_batch=args.max_batch, max_len=args.max_len,
-                             extra_inputs=extra_inputs,
-                             paged=args.paged, block_size=args.block_size,
-                             prefix_reuse=args.prefix_reuse,
-                             num_blocks=args.num_blocks)
-        if engine.plan is not None:
-            print(f"programmed {len(engine.plan)} crossbar layer plans "
-                  f"({rt.backend})")
-        for _ in range(args.requests):
-            tail = rng.integers(0, cfg.vocab_size, args.prompt_len)
-            prompt = tail if prefix is None else np.concatenate([prefix,
-                                                                 tail])
-            engine.submit(prompt, max_new_tokens=args.max_new,
-                          temperature=args.temperature)
+    with use_mesh(make_host_mesh()):
+        engine = build_engine(cfg, max_batch=args.max_batch,
+                              max_len=args.max_len, seed=args.seed,
+                              quant_state=qs, plan=args.plan,
+                              backend=args.backend, paged=args.paged,
+                              block_size=args.block_size,
+                              prefix_reuse=args.prefix_reuse,
+                              num_blocks=args.num_blocks)
+        submit_random(engine, requests=args.requests,
+                      prompt_len=args.prompt_len, max_new=args.max_new,
+                      shared_prefix=args.shared_prefix,
+                      temperature=args.temperature, seed=args.seed)
         done = engine.run()
     st = engine.stats()
     print(f"served {st['requests']} requests, {st['decode_tokens']} tokens, "

@@ -23,10 +23,63 @@ import jax.numpy as jnp
 from repro import runtime
 from repro.data.synthetic import TokenStream
 from repro.dist.sharding import use_mesh
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.launch.steps import MOE_FFN_SHARD_DATA, make_train_config
 from repro.models.registry import ARCHS, build_model, get_config
 from repro.train.loop import Trainer
+
+
+def train(cfg, tc, mesh, *, steps: int, batch: int, seq: int, seed: int = 0,
+          ckpt_dir=None, resume: bool = False, log_every: int = 10):
+    """Data-parallel + ZeRO-1 training of ``cfg`` on ``mesh``: params from
+    ``seed``, the synthetic token stream, the Runtime's sharded donated step.
+    Returns ``(params, opt_state, report)`` (see ``train.loop.Trainer``)."""
+    print(f"arch={cfg.name} layers={cfg.n_layers} d={cfg.d_model} "
+          f"mesh={dict(mesh.shape)} pim={cfg.pim_backend}")
+    init_fn, apply_fn, _ = build_model(cfg)
+    stream = TokenStream(cfg.vocab_size, seq, batch, seed=seed)
+
+    def batch_at(step):
+        b = stream.batch_at(step)
+        if cfg.frontend in ("patch", "frames"):
+            b["embeds"] = jnp.zeros((batch, 8, cfg.d_model), jnp.float32)
+        if cfg.encoder_layers:
+            b["embeds"] = jnp.zeros((batch, seq, cfg.d_model), jnp.float32)
+        return b
+
+    with use_mesh(mesh):
+        params = init_fn(jax.random.PRNGKey(seed))
+        # the Runtime owns the execution context; train_setup hands back
+        # the sharded, donated, jit'd step (metrics carry per-step ad_ops)
+        moe_fsdp = cfg.name in MOE_FFN_SHARD_DATA
+        rt = runtime.compile(cfg, params, mesh=mesh, tc=tc, donate=True,
+                             plan=None, fns=(init_fn, apply_fn, None),
+                             moe_ffn_shard_data=moe_fsdp)
+        jitted, opt_init, p_sh, o_sh = rt.train_setup(
+            moe_ffn_shard_data=moe_fsdp)
+        params = rt.params                     # placed onto p_sh by compile
+        opt_state = jax.device_put(opt_init(params), o_sh)
+
+        start = 0
+        if resume and ckpt_dir:
+            from repro.ckpt.checkpoint import latest_step, restore
+            step0 = latest_step(ckpt_dir)
+            if step0:
+                tree = restore(ckpt_dir,
+                               {"params": params, "opt": opt_state},
+                               shardings={"params": p_sh, "opt": o_sh})
+                params, opt_state = tree["params"], tree["opt"]
+                start = step0
+                print(f"resumed from step {start}")
+
+        trainer = Trainer(train_step=jitted, batch_at=batch_at, tc=tc,
+                          ckpt_dir=ckpt_dir, log_every=log_every)
+        return trainer.run(params, opt_state, start_step=start,
+                           num_steps=steps,
+                           on_metrics=lambda r: print(
+                               f"step {r['step']:5d} loss {r['loss']:.4f} "
+                               f"({r['step_time_s']:.2f}s)", flush=True))
 
 
 def main(argv=None):
@@ -52,63 +105,16 @@ def main(argv=None):
     ap.add_argument("--log-json", default=None)
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     cfg = get_config(args.arch, smoke=args.smoke).replace(pim_backend=args.pim)
     tc = make_train_config(args.arch, learning_rate=args.lr,
                            total_steps=args.steps,
                            warmup_steps=max(args.steps // 10, 1),
                            microbatch=args.microbatch,
                            checkpoint_every=args.ckpt_every)
-    mesh = make_host_mesh()
-    print(f"arch={cfg.name} layers={cfg.n_layers} d={cfg.d_model} "
-          f"mesh={dict(mesh.shape)} pim={cfg.pim_backend}")
-
-    init_fn, apply_fn, _ = build_model(cfg)
-    stream = TokenStream(cfg.vocab_size, args.seq, args.batch, seed=args.seed)
-
-    def batch_at(step):
-        b = stream.batch_at(step)
-        if cfg.frontend in ("patch", "frames"):
-            b["embeds"] = jnp.zeros((args.batch, 8, cfg.d_model), jnp.float32)
-        if cfg.encoder_layers:
-            b["embeds"] = jnp.zeros((args.batch, args.seq, cfg.d_model),
-                                    jnp.float32)
-        return b
-
-    with use_mesh(mesh):
-        params = init_fn(jax.random.PRNGKey(args.seed))
-        # the Runtime owns the execution context; train_setup hands back
-        # the sharded, donated, jit'd step (metrics carry per-step ad_ops)
-        moe_fsdp = args.arch in MOE_FFN_SHARD_DATA
-        rt = runtime.compile(cfg, params, mesh=mesh, tc=tc, donate=True,
-                             plan=None, fns=(init_fn, apply_fn, None),
-                             moe_ffn_shard_data=moe_fsdp)
-        jitted, opt_init, p_sh, o_sh = rt.train_setup(
-            moe_ffn_shard_data=moe_fsdp)
-        params = rt.params                     # placed onto p_sh by compile
-        opt_state = jax.device_put(opt_init(params), o_sh)
-
-        start = 0
-        if args.resume and args.ckpt_dir:
-            from repro.ckpt.checkpoint import latest_step, restore
-            step0 = latest_step(args.ckpt_dir)
-            if step0:
-                tree = restore(args.ckpt_dir,
-                               {"params": params, "opt": opt_state},
-                               shardings={"params": p_sh, "opt": o_sh})
-                params, opt_state = tree["params"], tree["opt"]
-                start = step0
-                print(f"resumed from step {start}")
-
-        trainer = Trainer(train_step=jitted, batch_at=batch_at, tc=tc,
-                          ckpt_dir=args.ckpt_dir)
-        params, opt_state, report = trainer.run(params, opt_state,
-                                                start_step=start,
-                                                num_steps=args.steps,
-                                                on_metrics=lambda r: print(
-                                                    f"step {r['step']:5d} "
-                                                    f"loss {r['loss']:.4f} "
-                                                    f"({r['step_time_s']:.2f}s)",
-                                                    flush=True))
+    _, _, report = train(cfg, tc, make_host_mesh(), steps=args.steps,
+                         batch=args.batch, seq=args.seq, seed=args.seed,
+                         ckpt_dir=args.ckpt_dir, resume=args.resume)
     print(f"median step {report['median_step_s']:.3f}s, "
           f"stragglers flagged: {len(report['stragglers'])}")
     first = report["history"][0]["loss"]

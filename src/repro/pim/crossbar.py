@@ -34,7 +34,6 @@ class PimConfig:
     k_w: int = 8             # weight bit-width (1-bit cells -> k_w columns)
     k_i: int = 8             # input bit-width (1-bit DAC -> k_i slices)
     r_adc: int = 8           # native ADC resolution
-    interpret: bool = True   # pallas interpret mode (CPU container)
 
 
 def offset_encode(w_int: jax.Array, k_w: int = 8) -> tuple[jax.Array, int]:

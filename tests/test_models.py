@@ -10,6 +10,11 @@ from repro.models.registry import ARCHS, build_model, get_config
 
 KEY = jax.random.PRNGKey(0)
 
+# XLA's CPU runtime has no BF16 x BF16 -> F32 dot for the batched
+# crossbar-group einsum once a layer spans more than one 128-row group; the
+# TPU runs it natively.  These CPU tests therefore compute in float32.
+CPU_DTYPE = "float32"
+
 # heaviest smoke configs (8-layer hybrid period / enc-dec stack): these
 # dominate suite wall-clock, so they carry the 'slow' mark for the CI fast
 # lane (-m "not slow"); every arch still runs in the full tier-1 suite
@@ -136,7 +141,7 @@ def test_moe_router_balance_aux():
 def test_pim_fake_quant_mode_close_to_exact():
     """TRQ fake-quant inference stays close to the exact datapath (the
     paper's accuracy-preservation claim, model-level)."""
-    cfg = get_config("llama3.2-3b", smoke=True)
+    cfg = get_config("llama3.2-3b", smoke=True).replace(dtype=CPU_DTYPE)
     init_fn, apply_fn, _ = build_model(cfg)
     params = init_fn(KEY)
     batch = _batch(cfg)

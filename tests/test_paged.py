@@ -21,10 +21,15 @@ from repro.serve.kvcache import PagedKVCache, ZERO_PAGE
 
 KEY = jax.random.PRNGKey(0)
 
+# XLA's CPU runtime has no BF16 x BF16 -> F32 dot for the batched
+# crossbar-group einsum once a layer spans more than one 128-row group; the
+# TPU runs it natively.  These CPU tests therefore compute in float32.
+CPU_DTYPE = "float32"
 
-def _build(arch, backend="fake_quant"):
+
+def _build(arch, backend="fake_quant", **over):
     cfg = get_config(arch, smoke=True).replace(remat="none",
-                                               pim_backend=backend)
+                                               pim_backend=backend, **over)
     init_fn, apply_fn, cache_fn = build_model(cfg)
     params = init_fn(KEY)
 
@@ -304,7 +309,7 @@ def test_cow_guard_copies_shared_page():
 
 @pytest.mark.parametrize("paged", [True, False])
 def test_prefill_only_and_single_token_requests(paged):
-    built = _build("llama3.2-3b")
+    built = _build("llama3.2-3b", dtype=CPU_DTYPE)
     cfg, apply_fn, cache_fn, params, extra = built
     eng = ServeEngine(cfg, apply_fn, cache_fn, params, max_batch=2,
                       max_len=64, paged=paged)

@@ -16,6 +16,11 @@ from repro.models.registry import build_model, get_config
 
 KEY = jax.random.PRNGKey(0)
 
+# XLA's CPU runtime has no BF16 x BF16 -> F32 dot for the batched
+# crossbar-group einsum once a layer spans more than one 128-row group; the
+# TPU runs it natively.  These CPU tests therefore compute in float32.
+CPU_DTYPE = "float32"
+
 
 def _params(**kw):
     base = dict(delta_r1=1.0, n_r1=4, n_r2=4, m=3, signed=True)
@@ -180,7 +185,8 @@ def test_quant_state_changes_per_layer_registers_in_serve_step(
     relative to the default registers; a round-trip through save/load
     reproduces the state bit-for-bit."""
     cfg = get_config(arch, smoke=True).replace(
-        pim_backend="fake_quant", param_dtype="bfloat16", remat="none")
+        pim_backend="fake_quant", param_dtype="bfloat16", remat="none",
+        dtype=CPU_DTYPE)
     init_fn, apply_fn, cache_fn = build_model(cfg)
     params = init_fn(KEY)
     b, s = 1, 8
@@ -220,7 +226,8 @@ def test_unrolled_model_exposes_per_depth_names(rng):
     shares period-local names by design."""
     from repro.pim import ad_ops_tally
     cfg = get_config("llama3.2-3b", smoke=True).replace(
-        pim_backend="fake_quant", scan_layers=False, remat="none")
+        pim_backend="fake_quant", scan_layers=False, remat="none",
+        dtype=CPU_DTYPE)
     assert cfg.n_layers == 2 and cfg.period == 1
     init_fn, apply_fn, _ = build_model(cfg)
     params = init_fn(KEY)
@@ -246,7 +253,8 @@ def test_serve_engine_applies_quant_state(rng):
     from repro import runtime
     from repro.serve.engine import ServeEngine
     cfg = get_config("llama3.2-3b", smoke=True).replace(
-        pim_backend="fake_quant", param_dtype="bfloat16", remat="none")
+        pim_backend="fake_quant", param_dtype="bfloat16", remat="none",
+        dtype=CPU_DTYPE)
     init_fn, apply_fn, cache_fn = build_model(cfg)
     params = init_fn(KEY)
     toks = jnp.asarray(rng.integers(0, cfg.vocab_size, (1, 8)), jnp.int32)

@@ -11,7 +11,8 @@ from repro.models.registry import ARCHS, build_model, get_config
 
 
 def _mesh_1d():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 
 def test_every_param_path_matches_a_rule():
@@ -44,7 +45,8 @@ def test_param_pspecs_shard_big_dims():
 
 def test_indivisible_dims_dropped():
     """whisper's 51865 vocab must NOT be sharded on a 16-way axis."""
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     # fake a 16-way mesh via rule check at the logical level instead:
     # use a real 1x1 mesh but call _drop_indivisible directly
     from repro.dist.sharding import _drop_indivisible, _ACTIVE

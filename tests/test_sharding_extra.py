@@ -12,7 +12,8 @@ from repro.models.registry import build_model, get_config
 
 
 def _mesh_1d():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 
 def _flat_specs(specs):
