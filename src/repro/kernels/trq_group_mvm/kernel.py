@@ -83,4 +83,5 @@ def trq_group_mvm_tiles(a: jax.Array, w: jax.Array, p: TRQParams,
         out_specs=[out_block, out_block] if with_ops else out_block,
         out_shape=[out_shape, out_shape] if with_ops else out_shape,
         interpret=interpret,
+        name="trq_group_mvm",
     )(scalars, a, w)

@@ -163,6 +163,8 @@ def main(argv=None):
           f"{st['tokens_per_s']:.1f} tok/s, ttft {st['mean_ttft_s']*1e3:.0f}ms, "
           f"{st['total_ad_ops']:.3e} A/D ops "
           f"({st['total_ad_energy_pj']/1e6:.3f} uJ)")
+    print("counters: " + ", ".join(f"{k} {v}"
+                                   for k, v in st["counters"].items()))
     if args.energy_report:
         print(format_energy_report(serve_report(engine)))
     for r in done[:3]:

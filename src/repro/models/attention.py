@@ -19,6 +19,7 @@ from repro.configs.base import ModelConfig
 from repro.core.trq import TRQParams
 from repro.dist.sharding import shard
 from repro.pim.plan import subplan
+from repro.telemetry.trace import scoped
 from .layers import apply_rope, init_linear, pim_linear
 
 NEG_INF = -1e30
@@ -58,6 +59,7 @@ def _group_q(q: jax.Array, n_kv: int) -> jax.Array:
     return q.reshape(b, s, n_kv, h // n_kv, hd)
 
 
+@scoped("attention.core")
 def full_attention(q, k, v, causal: bool, q_off=0) -> jax.Array:
     """Reference path for short sequences. q: (B,Sq,KV,G,hd), k/v: (B,Sk,KV,hd).
 
@@ -78,6 +80,7 @@ def full_attention(q, k, v, causal: bool, q_off=0) -> jax.Array:
     return o.astype(q.dtype)
 
 
+@scoped("attention.core")
 def chunked_attention(q, k, v, causal: bool, chunk_q: int, chunk_k: int,
                       context_parallel: bool = False) -> jax.Array:
     """Flash-style online-softmax attention, q-chunks BATCHED.
@@ -129,6 +132,7 @@ def chunked_attention(q, k, v, causal: bool, chunk_q: int, chunk_k: int,
     return out.astype(q.dtype)
 
 
+@scoped("attention.core")
 def decode_attention(q, k_cache, v_cache, cache_len) -> jax.Array:
     """One-token attention against a cache.
 
@@ -201,12 +205,13 @@ def apply_attention(p, x, cfg: ModelConfig, positions, *, causal=True,
         o = decode_attention(qg, k_cache, v_cache, idx + 1)
     else:
         # prefill into the cache
-        pad = cache["k"].shape[1] - s
-        k_full = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        v_full = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        new_cache = {"k": k_full.astype(cache["k"].dtype),
-                     "v": v_full.astype(cache["v"].dtype),
-                     "len": jnp.full((b,), s, jnp.int32)}
+        with jax.named_scope("attention.cache_write"):
+            pad = cache["k"].shape[1] - s
+            k_full = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
+            v_full = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
+            new_cache = {"k": k_full.astype(cache["k"].dtype),
+                         "v": v_full.astype(cache["v"].dtype),
+                         "len": jnp.full((b,), s, jnp.int32)}
         if s > cfg.attn_chunk_q and s % cfg.attn_chunk_q == 0 and \
                 s % ck == 0:
             o = chunked_attention(qg, k, v, causal, cfg.attn_chunk_q,
@@ -221,6 +226,7 @@ def apply_attention(p, x, cfg: ModelConfig, positions, *, causal=True,
                       plan=subplan(plan, "wo")), new_cache
 
 
+@scoped("attention.cache_write")
 def _scatter_time(cache: jax.Array, new: jax.Array, idx: jax.Array) -> jax.Array:
     """Write new (B,1,KV,hd) at per-batch time index idx into (B,S,KV,hd).
 

@@ -11,6 +11,7 @@ from repro.core.quant_state import active_quant_state
 from repro.core.trq import TRQParams
 from repro.pim.backend import active_backend, get_backend, record_ad_ops
 from repro.pim.plan import LayerPlan, run_prepared, subplan
+from repro.telemetry.trace import scoped
 from repro.dist.sharding import shard
 
 
@@ -71,33 +72,35 @@ def pim_linear(p: dict, x: jax.Array, cfg: ModelConfig,
     """
     w = p["w"]
     backend_name = active_backend() or cfg.pim_backend
-    if plan is not None and isinstance(plan, LayerPlan) and \
-            plan.backend == backend_name and trq is None:
-        if tuple(w.shape[-2:]) != (plan.k, plan.n):
-            raise ValueError(
-                f"stale plan at {name!r}: programmed for "
-                f"({plan.k}, {plan.n}) but params have "
-                f"{tuple(w.shape[-2:])}; re-run prepare_params")
-        out = run_prepared(x, plan, ste=True)
-    else:
-        if cfg.parallelism == "fsdp_cp" and w.ndim == 2:
-            # ZeRO-3-style: gather the (sharded) weight, compute seq-local.
-            # The AG has no dependence on the previous layer's activations,
-            # so the latency-hiding scheduler prefetches it under compute.
-            w = shard(w, None, None)
+    # device scopes name the datapath in the trace: pim_linear/<backend>
+    with jax.named_scope("pim_linear"), jax.named_scope(backend_name):
+        if plan is not None and isinstance(plan, LayerPlan) and \
+                plan.backend == backend_name and trq is None:
+            if tuple(w.shape[-2:]) != (plan.k, plan.n):
+                raise ValueError(
+                    f"stale plan at {name!r}: programmed for "
+                    f"({plan.k}, {plan.n}) but params have "
+                    f"{tuple(w.shape[-2:])}; re-run prepare_params")
+            out = run_prepared(x, plan, ste=True)
+        else:
+            if cfg.parallelism == "fsdp_cp" and w.ndim == 2:
+                # ZeRO-3-style: gather the (sharded) weight, compute seq-local.
+                # The AG has no dependence on the previous layer's activations,
+                # so the latency-hiding scheduler prefetches it under compute.
+                w = shard(w, None, None)
 
-        t = trq
-        if t is None:
-            qs = active_quant_state()
-            if qs is not None:
-                t = qs.lookup(name)
-        auto_range = t is None and cfg.trq.auto_range
-        if t is None:
-            t = trq_params_from_cfg(cfg.trq)
+            t = trq
+            if t is None:
+                qs = active_quant_state()
+                if qs is not None:
+                    t = qs.lookup(name)
+            auto_range = t is None and cfg.trq.auto_range
+            if t is None:
+                t = trq_params_from_cfg(cfg.trq)
 
-        out = get_backend(backend_name)(
-            x, w.astype(x.dtype), t, ste=True, auto_range=auto_range,
-            delta_grid=cfg.trq.delta_grid)
+            out = get_backend(backend_name)(
+                x, w.astype(x.dtype), t, ste=True, auto_range=auto_range,
+                delta_grid=cfg.trq.delta_grid)
 
     record_ad_ops(name, out.ad_ops)
     y = out.y
@@ -114,6 +117,7 @@ def init_rmsnorm(d: int):
     return {"scale": jnp.ones((d,), jnp.float32)}
 
 
+@scoped("norm")
 def rmsnorm(p: dict, x: jax.Array, eps: float = 1e-5) -> jax.Array:
     xf = x.astype(jnp.float32)
     n = xf * jax.lax.rsqrt(jnp.mean(jnp.square(xf), -1, keepdims=True) + eps)
@@ -125,6 +129,7 @@ def init_layernorm(d: int):
             "bias": jnp.zeros((d,), jnp.float32)}
 
 
+@scoped("norm")
 def layernorm(p: dict, x: jax.Array, eps: float = 1e-5) -> jax.Array:
     xf = x.astype(jnp.float32)
     mu = jnp.mean(xf, -1, keepdims=True)
@@ -142,6 +147,7 @@ def init_embed(key, cfg: ModelConfig):
     return {"tok": (tok * cfg.d_model ** -0.5).astype(pdtype(cfg))}
 
 
+@scoped("embed")
 def embed(p: dict, tokens: jax.Array) -> jax.Array:
     return jnp.take(p["tok"], tokens, axis=0)
 
@@ -151,6 +157,7 @@ def rope_freqs(cfg: ModelConfig) -> jax.Array:
     return 1.0 / (cfg.rope_theta ** (jnp.arange(0, hd, 2, jnp.float32) / hd))
 
 
+@scoped("rope")
 def apply_rope(x: jax.Array, positions: jax.Array, cfg: ModelConfig) -> jax.Array:
     """x: (B, S, H, hd); positions: (B, S) int32."""
     ang = positions[..., None].astype(jnp.float32) * rope_freqs(cfg)   # (B,S,hd/2)

@@ -228,9 +228,12 @@ def apply_lm(params, batch: dict, cfg: ModelConfig, *,
 
     plan_periods = subplan(pl, "periods")
     if cfg.scan_layers:
-        (x, aux, ops), new_cache = jax.lax.scan(
-            wrap(period_body), (x, jnp.float32(0), jnp.float32(0)),
-            (params["periods"], cache, plan_periods))
+        # the scan's own work (each layer's slice of the weights and the
+        # cache, the stacked new cache) runs under this scope
+        with jax.named_scope("layer_scan"):
+            (x, aux, ops), new_cache = jax.lax.scan(
+                wrap(period_body), (x, jnp.float32(0), jnp.float32(0)),
+                (params["periods"], cache, plan_periods))
     else:
         new_caches = []
         aux = jnp.float32(0)
@@ -257,12 +260,14 @@ def apply_lm(params, batch: dict, cfg: ModelConfig, *,
     # 'model', and leaving both sharded makes GSPMD all-gather the (B,S,V)
     # gradient in backward (EXPERIMENTS.md §Perf iter 1)
     x = shard(x, "batch", None, None)
-    if cfg.tie_embeddings:
-        logits = x.astype(jnp.float32) @ params["embed"]["tok"].astype(
-            jnp.float32).T
-    else:
-        logits = pim_linear(params["lm_head"], x, cfg, name="lm_head",
-                            plan=subplan(pl, "lm_head")).astype(jnp.float32)
+    with jax.named_scope("lm_head"):
+        if cfg.tie_embeddings:
+            logits = x.astype(jnp.float32) @ params["embed"]["tok"].astype(
+                jnp.float32).T
+        else:
+            logits = pim_linear(params["lm_head"], x, cfg, name="lm_head",
+                                plan=subplan(pl, "lm_head")
+                                ).astype(jnp.float32)
     logits = shard(logits, "batch", None, "vocab")
     return logits, (new_cache if cache is not None else None), aux
 

@@ -277,8 +277,9 @@ def pallas_backend(x, w, trq, *, a_scale=None, w_scale=None,
         # same pre-fit as the scan path (the TPU kernel's in-VMEM running
         # max is future work); keeps pallas/fake_quant bit-aligned
         trq = auto_range_fit(xf, wf, trq, grid, pim)
-    y, ops = trq_group_mvm_pallas(xf, wf, trq, grid, 1.0,
-                                  interpret=interpret, with_ops=True)
+    with jax.named_scope("convert"):
+        y, ops = trq_group_mvm_pallas(xf, wf, trq, grid, 1.0,
+                                      interpret=interpret, with_ops=True)
     return PimOut(y.astype(x.dtype), ops)
 
 

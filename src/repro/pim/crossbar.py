@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.trq import TRQParams, trq_quant, trq_ad_ops
+from repro.telemetry.trace import scoped
 
 
 @dataclasses.dataclass(frozen=True)
@@ -171,6 +172,7 @@ def _group_psums(a_g: jax.Array, w_g: jax.Array) -> jax.Array:
                       preferred_element_type=jnp.float32)
 
 
+@scoped("range_fit")
 def auto_range_fit_grouped(a_g: jax.Array, w_g: jax.Array, trq: TRQParams,
                            grid) -> TRQParams:
     """:func:`auto_range_fit` on pre-grouped operands (plan fast path)."""
@@ -206,6 +208,13 @@ def fake_quant_mvm_grouped(a_g: jax.Array, w_g: jax.Array, trq: TRQParams,
     grid = jnp.asarray(grid, jnp.float32)
     if auto_range:
         trq = auto_range_fit_grouped(a_g, w_g, trq, grid)
+    return _convert(a_g, w_g, trq, grid, out_dtype, ste, with_ops)
+
+
+@scoped("convert")
+def _convert(a_g, w_g, trq, grid, out_dtype, ste, with_ops):
+    """The TRQ conversion of every group's partial sums, then the sum over
+    groups (see :func:`fake_quant_mvm_grouped`)."""
     p = _group_psums(a_g, w_g)                          # (..., G, N) f32
     scaled = p / grid
     q = trq_quant(scaled, trq) * grid                   # f32, all groups

@@ -557,10 +557,11 @@ def _prepared_pallas(x, lp: LayerPlan, *, a_scale=None, w_scale=None,
         trq = auto_range_fit_grouped(group_activations(xf, lp.pim), lp.w_g,
                                      trq, grid)
     x2 = xf.reshape(-1, lp.k)
-    y, ops = _pallas_prepared_exec(x2, lp.w_f32, trq, grid,
-                                   block_m=pick_block_m(x2.shape[0]),
-                                   n=lp.n,
-                                   interpret=resolve_interpret(interpret))
+    with jax.named_scope("convert"):
+        y, ops = _pallas_prepared_exec(x2, lp.w_f32, trq, grid,
+                                       block_m=pick_block_m(x2.shape[0]),
+                                       n=lp.n,
+                                       interpret=resolve_interpret(interpret))
     return PimOut(y.reshape(*lead, lp.n).astype(x.dtype), ops)
 
 

@@ -181,7 +181,8 @@ class Runtime:
         extra = extra or {}
         def make():
             def step(params, plan, tokens, extra):
-                with self._ambient(), traced_ad_ops() as tally:
+                with self._ambient(), traced_ad_ops() as tally, \
+                        jax.named_scope("runtime.prefill"):
                     cache = self.cache_fn(tokens.shape[0], max_len)
                     batch = {"tokens": tokens, **extra}
                     logits, cache, _ = self.apply_fn(
@@ -197,7 +198,8 @@ class Runtime:
         ``((last_logits, cache), AdOpsReport)``."""
         def make():
             def step(params, plan, tokens, positions, cache):
-                with self._ambient(), traced_ad_ops() as tally:
+                with self._ambient(), traced_ad_ops() as tally, \
+                        jax.named_scope("runtime.prefill_cont"):
                     batch = {"tokens": tokens, "positions": positions}
                     logits, cache, _ = self.apply_fn(
                         params, batch, cache=cache, mode="prefill_cont",
@@ -214,7 +216,8 @@ class Runtime:
         extra = extra or {}
         def make():
             def step(params, plan, cache, tokens, extra):
-                with self._ambient(), traced_ad_ops() as tally:
+                with self._ambient(), traced_ad_ops() as tally, \
+                        jax.named_scope("runtime.decode"):
                     batch = {"tokens": tokens, **extra}
                     logits, cache, _ = self.apply_fn(
                         params, batch, cache=cache, mode="decode", plan=plan)

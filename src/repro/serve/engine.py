@@ -63,6 +63,7 @@ import numpy as np
 from repro.core.energy import adc_energy_pj
 from repro.core.quant_state import QuantState
 from repro.dist.sharding import _ACTIVE as _MESH_ACTIVE
+from repro.telemetry.trace import program, span
 from .kvcache import PagedKVCache, ZERO_PAGE, pool_pspecs
 
 # legacy-signature shim state: ServeEngine(cfg, apply_fn, cache_fn, params)
@@ -80,6 +81,7 @@ class Request:
     generated: list = dataclasses.field(default_factory=list)
     done: bool = False
     submit_t: float = 0.0
+    prefill_t: float = 0.0              # host time its prefill started
     first_token_t: float = 0.0
     finish_t: float = 0.0
     # energy metering (SAR comparator cycles attributed to this request)
@@ -228,10 +230,18 @@ class ServeEngine:
         self.finished: list[Request] = []
         self.total_ad_ops = 0.0
         self.prefill_ad_ops = 0.0
+        # work counted where it happens (rows are batch rows of the calls
+        # into the Runtime: padded prefill rows, the decode program's batch
+        # rows, live or idle, per decode step)
+        self.counters = {"prefill_calls": 0, "prefill_rows": 0,
+                         "prefill_real_rows": 0, "decode_steps": 0,
+                         "decode_rows": 0, "decode_active_rows": 0,
+                         "slot_resets": 0, "installs": 0}
         self._uid = 0
         self._key = jax.random.PRNGKey(rng_seed)
         self._prefill_cache_fn = cache_fn
-        self._scatter_jit = jax.jit(scatter_cache, static_argnames=())
+        self._scatter_jit = program("kv.slot_scatter", scatter_cache,
+                                   static_argnames=())
 
     # -- request lifecycle ---------------------------------------------------
 
@@ -250,13 +260,14 @@ class ServeEngine:
     # execution context and returns each call's AdOpsReport)
 
     def _sample(self, logits: jax.Array, temps: np.ndarray) -> np.ndarray:
-        self._key, k = jax.random.split(self._key)
-        greedy = jnp.argmax(logits, -1)
-        scaled = logits / jnp.maximum(
-            jnp.asarray(temps, jnp.float32)[:, None], 1e-6)
-        sampled = jax.random.categorical(k, scaled)
-        return np.asarray(jnp.where(jnp.asarray(temps) > 0, sampled, greedy),
-                          np.int32)
+        with span("serve.sample"):
+            self._key, k = jax.random.split(self._key)
+            greedy = jnp.argmax(logits, -1)
+            scaled = logits / jnp.maximum(
+                jnp.asarray(temps, jnp.float32)[:, None], 1e-6)
+            sampled = jax.random.categorical(k, scaled)
+            return np.asarray(jnp.where(jnp.asarray(temps) > 0, sampled,
+                                        greedy), np.int32)
 
     # -- scheduler -------------------------------------------------------------
 
@@ -302,12 +313,14 @@ class ServeEngine:
             else:
                 self._zero_small = jax.tree.map(
                     jnp.zeros_like, self._prefill_cache_fn(1, self.max_len))
-        if self.paged:
-            self.state_cache = self._scatter_jit(self.state_cache,
-                                                 self._zero_small, slot)
-        else:
-            self.cache = self._scatter_jit(self.cache, self._zero_small,
-                                           slot)
+        self.counters["slot_resets"] += 1
+        with span("serve.zero_slot", slot=slot):
+            if self.paged:
+                self.state_cache = self._scatter_jit(self.state_cache,
+                                                     self._zero_small, slot)
+            else:
+                self.cache = self._scatter_jit(self.cache, self._zero_small,
+                                               slot)
 
     def _prefill(self, r: Request):
         """Prefill ``r`` (reusing cached prefix blocks when possible),
@@ -315,6 +328,7 @@ class ServeEngine:
         ``_install``), sample the first token, meter ops/TTFT.
         Returns the batch=1 small cache (or None in paged mode where blocks
         are already written)."""
+        r.prefill_t = time.perf_counter()
         plen = int(min(len(r.prompt), self.max_len - r.max_new_tokens))
         padded = self._bucket(plen)
         toks = np.zeros((1, padded), np.int32)
@@ -337,83 +351,94 @@ class ServeEngine:
             keys = self.kv.prefix_keys(padded, toks[0], bs, cap)
             reuse_n, shared = self.kv.lookup_prefix(keys)
 
-        if reuse_n:
-            self.kv.incref(shared)
-            r.block_table = list(shared)
-            L = reuse_n * bs
-            state1 = self.kv.make_state(1, fill_len=L)
-            table1 = np.full((1, padded // bs), ZERO_PAGE, np.int32)
-            table1[0, :reuse_n] = shared
-            dense1 = self.kv.assemble(state1, table1)
-            positions = np.arange(L, padded, dtype=np.int32)[None]
-            (last_logits, small), rep = self.rt.prefill_cont(
-                jnp.asarray(toks[:, L:]), jnp.asarray(positions), dense1)
-            r.reused_tokens = L
-        else:
-            (last_logits, small), rep = self.rt.prefill(
-                jnp.asarray(toks), extra, max_len=self.max_len)
-        self._meter(r, rep.ad_ops, prefill=True)
+        rows = padded - reuse_n * bs
+        real = min(plen, rows)
+        self.counters["prefill_calls"] += 1
+        self.counters["prefill_rows"] += rows
+        self.counters["prefill_real_rows"] += real
+        with span("serve.prefill", uid=r.uid, rows=rows, real=real,
+                  reused=reuse_n * bs):
+            if reuse_n:
+                self.kv.incref(shared)
+                r.block_table = list(shared)
+                L = reuse_n * bs
+                state1 = self.kv.make_state(1, fill_len=L)
+                table1 = np.full((1, padded // bs), ZERO_PAGE, np.int32)
+                table1[0, :reuse_n] = shared
+                dense1 = self.kv.assemble(state1, table1)
+                positions = np.arange(L, padded, dtype=np.int32)[None]
+                (last_logits, small), rep = self.rt.prefill_cont(
+                    jnp.asarray(toks[:, L:]), jnp.asarray(positions), dense1)
+                r.reused_tokens = L
+            else:
+                (last_logits, small), rep = self.rt.prefill(
+                    jnp.asarray(toks), extra, max_len=self.max_len)
+            self._meter(r, rep.ad_ops, prefill=True)
 
-        if self.paged and self.kv.specs:
-            n_blk = min(-(-seq_valid // bs), self.kv.pages_per_slot)
-            new_blks = np.arange(reuse_n, n_blk, dtype=np.int32)
-            if len(new_blks):
-                new_pages = self.kv.alloc_pages(len(new_blks))
-                r.block_table.extend(new_pages)
-                self.kv.write_blocks(small, np.zeros(len(new_blks)),
-                                     new_blks, new_pages)
-            if keys:
-                self.kv.register_prefix(keys, r.block_table)
-        r.cache_len = total_len
+            if self.paged and self.kv.specs:
+                n_blk = min(-(-seq_valid // bs), self.kv.pages_per_slot)
+                new_blks = np.arange(reuse_n, n_blk, dtype=np.int32)
+                if len(new_blks):
+                    new_pages = self.kv.alloc_pages(len(new_blks))
+                    r.block_table.extend(new_pages)
+                    self.kv.write_blocks(small, np.zeros(len(new_blks)),
+                                         new_blks, new_pages)
+                if keys:
+                    self.kv.register_prefix(keys, r.block_table)
+            r.cache_len = total_len
 
-        nxt = self._sample(last_logits, np.array([r.temperature]))
-        r.first_token_t = time.perf_counter()
-        if r.max_new_tokens > 0:
-            r.generated.append(int(nxt[0]))
-        return small
+            nxt = self._sample(last_logits, np.array([r.temperature]))
+            r.first_token_t = time.perf_counter()
+            if r.max_new_tokens > 0:
+                r.generated.append(int(nxt[0]))
+            return small
 
     def _install(self, r: Request, small, slot: int) -> None:
         """Scatter the batch=1 prefill cache into decode residency."""
-        if self.paged:
-            self.state_cache = self._scatter_jit(
-                self.state_cache, self.kv.state_only(small), slot)
-        else:
-            self.cache = self._scatter_jit(self.cache, small, slot)
+        self.counters["installs"] += 1
+        with span("serve.install", uid=r.uid, slot=slot):
+            if self.paged:
+                self.state_cache = self._scatter_jit(
+                    self.state_cache, self.kv.state_only(small), slot)
+            else:
+                self.cache = self._scatter_jit(self.cache, small, slot)
 
     def _admit(self):
-        for slot in range(self.max_batch):
-            if self.slots[slot] is not None:
-                continue
-            while self.queue:
-                r = self.queue.pop(0)
-                small = self._prefill(r)
-                if len(r.generated) >= r.max_new_tokens:
-                    # prefill-only (max_new_tokens <= 1): finished at
-                    # admission — never occupies a decode slot.  Reused /
-                    # registered prefix pages stay cached for later
-                    # requests (prefix warming).
-                    self._finalize(r)
+        with span("serve.admit"):
+            for slot in range(self.max_batch):
+                if self.slots[slot] is not None:
                     continue
-                self._install(r, small, slot)
-                self.slots[slot] = r
-                break
+                while self.queue:
+                    r = self.queue.pop(0)
+                    small = self._prefill(r)
+                    if len(r.generated) >= r.max_new_tokens:
+                        # prefill-only (max_new_tokens <= 1): finished at
+                        # admission — never occupies a decode slot.
+                        # Reused / registered prefix pages stay cached for
+                        # later requests (prefix warming).
+                        self._finalize(r)
+                        continue
+                    self._install(r, small, slot)
+                    self.slots[slot] = r
+                    break
 
     def _decode_cache(self):
         """The dense cache view for this decode step (+ per-slot tables)."""
         if not self.paged:
             return self.cache
-        tables = np.full((self.max_batch, self.kv.pages_per_slot),
-                         ZERO_PAGE, np.int32)
-        for i, r in enumerate(self.slots):
-            if r is None:
-                continue
-            if self.kv.specs:
-                blk = self._write_blk(r)
-                while len(r.block_table) <= blk:
-                    r.block_table.extend(self.kv.alloc_pages(1))
-                self.kv.ensure_private(r.block_table, blk)
-                tables[i, :len(r.block_table)] = r.block_table
-        return self.kv.assemble(self.state_cache, tables)
+        with span("serve.decode_cache"):
+            tables = np.full((self.max_batch, self.kv.pages_per_slot),
+                             ZERO_PAGE, np.int32)
+            for i, r in enumerate(self.slots):
+                if r is None:
+                    continue
+                if self.kv.specs:
+                    blk = self._write_blk(r)
+                    while len(r.block_table) <= blk:
+                        r.block_table.extend(self.kv.alloc_pages(1))
+                    self.kv.ensure_private(r.block_table, blk)
+                    tables[i, :len(r.block_table)] = r.block_table
+            return self.kv.assemble(self.state_cache, tables)
 
     def _write_blk(self, r: Request) -> int:
         """Block index this decode step writes: the model's cache scatter
@@ -427,51 +452,61 @@ class ServeEngine:
         if not self.paged:
             self.cache = new_cache
             return
-        self.state_cache = self.kv.state_only(new_cache)
-        if not self.kv.specs:
-            return
-        slots = np.asarray(active, np.int32)
-        blks = np.asarray([self._write_blk(self.slots[i])
-                           for i in active], np.int32)
-        pages = np.asarray([self.slots[i].block_table[b]
-                            for i, b in zip(active, blks)], np.int32)
-        self.kv.write_blocks(new_cache, slots, blks, pages,
-                             skip_static=True)
+        with span("serve.writeback"):
+            self.state_cache = self.kv.state_only(new_cache)
+            if not self.kv.specs:
+                return
+            slots = np.asarray(active, np.int32)
+            blks = np.asarray([self._write_blk(self.slots[i])
+                               for i in active], np.int32)
+            pages = np.asarray([self.slots[i].block_table[b]
+                                for i, b in zip(active, blks)], np.int32)
+            self.kv.write_blocks(new_cache, slots, blks, pages,
+                                 skip_static=True)
 
     def step(self) -> int:
         """Admit + one decode step for all active slots.  Returns #active."""
-        self._admit()
-        active = [i for i, r in enumerate(self.slots) if r is not None]
-        if not active:
-            return 0
-        toks = np.zeros((self.max_batch, 1), np.int32)
-        temps = np.zeros((self.max_batch,), np.float32)
-        for i in active:
-            toks[i, 0] = self.slots[i].generated[-1]
-            temps[i] = self.slots[i].temperature
-        extra = self.extra_inputs(self.max_batch, 1)
-        cache = self._decode_cache()
-        (logits, new_cache), rep = self.rt.decode(jnp.asarray(toks), cache,
-                                                  extra)
-        self._writeback(new_cache, active)
-        # batched MVMs convert all resident rows together; attribute the
-        # step's conversions evenly across the slots that stepped (total is
-        # conserved: sum over requests == sum of per-call PimOut.ad_ops)
-        share = float(rep.ad_ops) / len(active)
-        self.total_ad_ops += float(rep.ad_ops)
-        nxt = self._sample(logits, temps)
-        for i in active:
-            r = self.slots[i]
-            r.ad_ops += share
-            r.generated.append(int(nxt[i]))
-            r.cache_len += 1
-            if len(r.generated) >= r.max_new_tokens:
-                self._finalize(r)
-                self.slots[i] = None
-        for i in range(self.max_batch):
-            if self.slots[i] is None:
-                self._zero_slot(i)
-        return len(active)
+        with span("serve.step", queued=len(self.queue),
+                  active=self.max_batch - self.slots.count(None)):
+            self._admit()
+            active = [i for i, r in enumerate(self.slots) if r is not None]
+            if not active:
+                return 0
+            toks = np.zeros((self.max_batch, 1), np.int32)
+            temps = np.zeros((self.max_batch,), np.float32)
+            for i in active:
+                toks[i, 0] = self.slots[i].generated[-1]
+                temps[i] = self.slots[i].temperature
+            extra = self.extra_inputs(self.max_batch, 1)
+            cache = self._decode_cache()
+            c = self.counters
+            with span("serve.decode", step=c["decode_steps"],
+                      active=len(active)):
+                (logits, new_cache), rep = self.rt.decode(jnp.asarray(toks),
+                                                          cache, extra)
+            c["decode_steps"] += 1
+            c["decode_rows"] += toks.shape[0]
+            c["decode_active_rows"] += len(active)
+            self._writeback(new_cache, active)
+            # batched MVMs convert all resident rows together; attribute
+            # the step's conversions evenly across the slots that stepped
+            # (total is conserved: sum over requests == sum of per-call
+            # PimOut.ad_ops)
+            share = float(rep.ad_ops) / len(active)
+            self.total_ad_ops += float(rep.ad_ops)
+            nxt = self._sample(logits, temps)
+            for i in active:
+                r = self.slots[i]
+                r.ad_ops += share
+                r.generated.append(int(nxt[i]))
+                r.cache_len += 1
+                if len(r.generated) >= r.max_new_tokens:
+                    self._finalize(r)
+                    self.slots[i] = None
+            for i in range(self.max_batch):
+                if self.slots[i] is None:
+                    self._zero_slot(i)
+            return len(active)
 
     def run(self, max_steps: int = 10_000) -> list[Request]:
         steps = 0
@@ -507,6 +542,7 @@ class ServeEngine:
                    [r.ad_ops for r in self.finished]))),
                "reused_prompt_tokens": sum(r.reused_tokens
                                            for r in self.finished)}
+        out["counters"] = dict(self.counters)
         if self.paged:
             out["paged"] = {
                 "block_size": self.block_size,

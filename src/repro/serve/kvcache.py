@@ -47,6 +47,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ModelConfig
+from repro.telemetry.trace import program
 
 
 def _axis_size(mesh: Mesh, axes) -> int:
@@ -247,11 +248,11 @@ class PagedKVCache:
                       "peak_pages_in_use": 0}
 
         # --- jitted array ops --------------------------------------------
-        self._assemble_jit = jax.jit(self._assemble)
-        self._write_jit = jax.jit(self._write_blocks,
+        self._assemble_jit = program("kv.assemble", self._assemble)
+        self._write_jit = program("kv.write_blocks", self._write_blocks,
                                   static_argnames=("skip_static",))
-        self._zero_jit = jax.jit(self._zero_pages)
-        self._copy_jit = jax.jit(self._copy_page)
+        self._zero_jit = program("kv.zero_pages", self._zero_pages)
+        self._copy_jit = program("kv.copy_page", self._copy_page)
 
     # -- geometry -------------------------------------------------------------
 

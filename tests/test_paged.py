@@ -77,7 +77,7 @@ FAST_ARCHS = ["llama3.2-3b", "rwkv6-7b", "whisper-medium"]
 
 
 def _assert_equiv(arch):
-    built = _build(arch)
+    built = _build(arch, dtype=CPU_DTYPE)
     rng = np.random.default_rng(3)
     prompts = [rng.integers(0, built[0].vocab_size, n)
                for n in (12, 30, 7, 21)]
@@ -157,7 +157,7 @@ def test_prefix_reuse_gated_off_for_recurrent_archs():
 def test_request_ad_ops_match_reference_pim_calls():
     """A single served request's metered ad_ops == the summed PimOut.ad_ops
     of an independent prefill+decode loop over the same tokens."""
-    built = _build("llama3.2-3b")
+    built = _build("llama3.2-3b", dtype=CPU_DTYPE)
     cfg, apply_fn, cache_fn, params, _ = built
     rng = np.random.default_rng(7)
     prompt = rng.integers(0, cfg.vocab_size, 9)
@@ -200,7 +200,7 @@ def test_request_ad_ops_match_reference_pim_calls():
 
 
 def test_stats_ad_ops_conserved_across_requests():
-    built = _build("llama3.2-3b")
+    built = _build("llama3.2-3b", dtype=CPU_DTYPE)
     _, gen, eng = _run_trace(built, paged=True,
                              prompts=_shared_prefix_prompts(
                                  built[0].vocab_size, n=5), max_new=3)

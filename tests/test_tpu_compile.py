@@ -57,6 +57,8 @@ def test_trq_group_mvm_compiles_for_v5e(one_chip, m, k, n):
                                    with_ops=True)
     compiled = jax.jit(tiles).lower(*_args(one_chip, m, k, n)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+    # the kernel's own name, which a device trace shows it under
+    assert "trq_group_mvm" in compiled.as_text()
 
 
 def test_prepared_pallas_exec_compiles_for_v5e(one_chip):
@@ -66,3 +68,4 @@ def test_prepared_pallas_exec_compiles_for_v5e(one_chip):
         *_args(one_chip, m, k, n), block_m=pick_block_m(m), n=n,
         interpret=False).compile()
     assert "tpu_custom_call" in compiled.as_text()
+    assert "trq_group_mvm" in compiled.as_text()
