@@ -128,6 +128,33 @@ def scatter_cache(big, small, slot: int):
     return jax.tree.map(one, big, small)
 
 
+def reset_rows(big, small, idle):
+    """Every row of the big cache that the ``(max_batch,)`` bool mask
+    ``idle`` marks takes the batch=1 cache ``small``: the same values
+    ``scatter_cache(big, small, slot)`` leaves there, for all marked slots
+    at once and in one shape whatever the mask holds.  Scalar (dummy)
+    leaves pass through."""
+    def one(b, s):
+        if b.ndim == 0:
+            return b
+        ax = _batch_axis(b.shape, s.shape)
+        mask = jnp.reshape(idle, [-1 if i == ax else 1
+                                  for i in range(b.ndim)])
+        return jnp.where(mask, s.astype(b.dtype), b)
+    return jax.tree.map(one, big, small)
+
+
+def sample_tokens(logits, temps, key):
+    """Tokens for ``(B, vocab)`` logits at ``(B,)`` temperatures (0 =
+    greedy: argmax), and the next key: ``key`` splits into the next key
+    and the one that draws this call's sampled rows."""
+    key, k = jax.random.split(key)
+    greedy = jnp.argmax(logits, -1)
+    sampled = jax.random.categorical(
+        k, logits / jnp.maximum(temps[:, None], 1e-6))
+    return jnp.where(temps > 0, sampled, greedy).astype(jnp.int32), key
+
+
 def _attn_only(cfg) -> bool:
     """Prefix reuse needs every mixer to be attention: K/V blocks are a
     pure function of the prefix, while recurrent (mamba/rwkv) prefixes
@@ -236,12 +263,15 @@ class ServeEngine:
         self.counters = {"prefill_calls": 0, "prefill_rows": 0,
                          "prefill_real_rows": 0, "decode_steps": 0,
                          "decode_rows": 0, "decode_active_rows": 0,
-                         "slot_resets": 0, "installs": 0}
+                         "slot_resets": 0, "reset_calls": 0,
+                         "installs": 0}
         self._uid = 0
         self._key = jax.random.PRNGKey(rng_seed)
         self._prefill_cache_fn = cache_fn
         self._scatter_jit = program("kv.slot_scatter", scatter_cache,
                                    static_argnames=())
+        self._reset_jit = program("kv.slot_reset", reset_rows)
+        self._sample_jit = program("sample", sample_tokens)
 
     # -- request lifecycle ---------------------------------------------------
 
@@ -261,13 +291,9 @@ class ServeEngine:
 
     def _sample(self, logits: jax.Array, temps: np.ndarray) -> np.ndarray:
         with span("serve.sample"):
-            self._key, k = jax.random.split(self._key)
-            greedy = jnp.argmax(logits, -1)
-            scaled = logits / jnp.maximum(
-                jnp.asarray(temps, jnp.float32)[:, None], 1e-6)
-            sampled = jax.random.categorical(k, scaled)
-            return np.asarray(jnp.where(jnp.asarray(temps) > 0, sampled,
-                                        greedy), np.int32)
+            toks, self._key = self._sample_jit(
+                logits, np.asarray(temps, np.float32), self._key)
+            return np.asarray(toks)
 
     # -- scheduler -------------------------------------------------------------
 
@@ -297,10 +323,11 @@ class ServeEngine:
             self.kv.release(r.block_table)
             r.block_table = []
 
-    def _zero_slot(self, slot: int) -> None:
-        """Zero an idle slot's cache rows.  Idle rows still ride through
-        every batched decode step (which garbage-writes their K/V at
-        position 0 and evolves their recurrent state), and their content
+    def _zero_slot(self, idle: np.ndarray) -> None:
+        """Zero the cache rows of every idle slot (``idle``: a
+        ``(max_batch,)`` bool mask) in one program.  Idle rows still ride
+        through every batched decode step (which garbage-writes their K/V
+        at position 0 and evolves their recurrent state), and their content
         leaks into ACTIVE rows through batch-coupled ops — the dynamic
         max-abs quantization scales of the fake_quant/pallas datapaths and
         MoE capacity dispatch.  Keeping idle rows deterministically zero
@@ -313,14 +340,16 @@ class ServeEngine:
             else:
                 self._zero_small = jax.tree.map(
                     jnp.zeros_like, self._prefill_cache_fn(1, self.max_len))
-        self.counters["slot_resets"] += 1
-        with span("serve.zero_slot", slot=slot):
+        rows = int(idle.sum())
+        self.counters["slot_resets"] += rows
+        self.counters["reset_calls"] += 1
+        with span("serve.zero_slot", rows=rows):
             if self.paged:
-                self.state_cache = self._scatter_jit(self.state_cache,
-                                                     self._zero_small, slot)
+                self.state_cache = self._reset_jit(self.state_cache,
+                                                   self._zero_small, idle)
             else:
-                self.cache = self._scatter_jit(self.cache, self._zero_small,
-                                               slot)
+                self.cache = self._reset_jit(self.cache, self._zero_small,
+                                             idle)
 
     def _prefill(self, r: Request):
         """Prefill ``r`` (reusing cached prefix blocks when possible),
@@ -503,9 +532,9 @@ class ServeEngine:
                 if len(r.generated) >= r.max_new_tokens:
                     self._finalize(r)
                     self.slots[i] = None
-            for i in range(self.max_batch):
-                if self.slots[i] is None:
-                    self._zero_slot(i)
+            idle = np.array([r is None for r in self.slots])
+            if idle.any():
+                self._zero_slot(idle)
             return len(active)
 
     def run(self, max_steps: int = 10_000) -> list[Request]:

@@ -7,6 +7,7 @@ both engines; before that fix, stale idle content leaked into active rows
 through the dynamic max-abs quantization scales of the fake_quant
 datapath.)
 """
+import contextlib
 import functools
 
 import numpy as np
@@ -16,7 +17,7 @@ import jax.numpy as jnp
 
 from repro.models.registry import build_model, get_config
 from repro.pim.backend import traced_ad_ops
-from repro.serve.engine import ServeEngine
+from repro.serve.engine import ServeEngine, scatter_cache
 from repro.serve.kvcache import PagedKVCache, ZERO_PAGE
 
 KEY = jax.random.PRNGKey(0)
@@ -330,3 +331,146 @@ def test_prefill_only_and_single_token_requests(paged):
     st = eng.stats()
     assert st["requests"] == 3 and st["decode_tokens"] == 4
     assert st["mean_ttft_s"] > 0
+
+
+# ---------------------------------------------------------------------------
+# the idle-slot reset and the sampler: one fixed-shape program each
+# ---------------------------------------------------------------------------
+
+def _per_slot_reset(eng):
+    """The reference reset: ``scatter_cache`` of a batch=1 zero state into
+    each idle slot, one slot at a time."""
+    zero = (eng.kv.make_state(1) if eng.paged else
+            jax.tree.map(jnp.zeros_like, eng._prefill_cache_fn(1,
+                                                             eng.max_len)))
+
+    def reset(idle):
+        state = eng.state_cache if eng.paged else eng.cache
+        for i in np.flatnonzero(idle):
+            state = scatter_cache(state, zero, int(i))
+        if eng.paged:
+            eng.state_cache = state
+        else:
+            eng.cache = state
+    eng._zero_slot = reset
+
+
+def _serve_recording_state(built, *, paged, reference):
+    """Serve greedy and sampled requests of staggered lengths; the slot
+    state (host copy) after every step."""
+    cfg, apply_fn, cache_fn, params, extra_inputs = built
+    eng = ServeEngine(cfg, apply_fn, cache_fn, params, max_batch=4,
+                      max_len=64, paged=paged, block_size=16,
+                      prefix_reuse=False, extra_inputs=extra_inputs)
+    if reference:
+        _per_slot_reset(eng)
+    rng = np.random.default_rng(7)
+    spec = [(12, 3, 0.0), (7, 5, 0.7), (20, 5, 0.0), (9, 2, 0.0),
+            (30, 4, 0.7), (5, 4, 0.0), (14, 4, 0.0), (11, 4, 0.7)]
+    reqs = [eng.submit(rng.integers(0, cfg.vocab_size, n),
+                       max_new_tokens=m, temperature=t) for n, m, t in spec]
+    states, idle = [], []
+    while eng.queue or any(s is not None for s in eng.slots):
+        if eng.step():
+            idle.append(eng.slots.count(None))
+        states.append(jax.device_get(eng.state_cache if paged
+                                     else eng.cache))
+    return [r.generated for r in reqs], states, (eng.counters, idle)
+
+
+@pytest.mark.parametrize("paged", [True, False], ids=["paged", "dense"])
+@pytest.mark.parametrize("arch", ["llama3.2-3b", "rwkv6-7b"])
+def test_idle_reset_program_matches_per_slot_scatter(arch, paged):
+    """After every step the one-program reset leaves the slot state
+    bitwise as the per-slot scatter of zeros does, and serves the same
+    tokens; one launch per step with an idle slot, one row per idle slot."""
+    built = _build(arch, dtype=CPU_DTYPE)
+    gen, states, (c, idle) = _serve_recording_state(built, paged=paged,
+                                                    reference=False)
+    gen_ref, states_ref, _ = _serve_recording_state(built, paged=paged,
+                                                    reference=True)
+    assert gen == gen_ref
+    assert len(states) == len(states_ref)
+    for got, want in zip(states, states_ref):
+        jax.tree.map(np.testing.assert_array_equal, got, want)
+    assert c["reset_calls"] == sum(n > 0 for n in idle) < c["decode_steps"]
+    assert c["slot_resets"] == sum(idle) > c["reset_calls"]
+
+
+@pytest.mark.parametrize("temperature", [0.0, 0.7], ids=["greedy", "sampled"])
+def test_sample_program_follows_the_eager_draw(temperature):
+    """The sampling program draws what the eager ops drew: argmax for
+    greedy rows, ``categorical`` under the second half of a split key for
+    sampled ones, and hands back the first half as the next key."""
+    from repro.serve.engine import sample_tokens
+    logits = jax.random.normal(jax.random.PRNGKey(3), (4, 97), jnp.float32)
+    temps = np.array([temperature, 0.0, temperature, 1.3], np.float32)
+    key = jax.random.PRNGKey(11)
+    toks, nxt = jax.jit(sample_tokens)(logits, temps, key)
+    want_key, k = jax.random.split(key)
+    want = np.where(temps > 0,
+                    np.asarray(jax.random.categorical(
+                        k, logits / np.maximum(temps, 1e-6)[:, None])),
+                    np.asarray(jnp.argmax(logits, -1)))
+    assert toks.dtype == jnp.int32
+    np.testing.assert_array_equal(np.asarray(toks), want)
+    np.testing.assert_array_equal(np.asarray(nxt), np.asarray(want_key))
+
+
+@contextlib.contextmanager
+def _compiles():
+    """Backend compilations while the block runs."""
+    n = [0]
+
+    def listen(event, secs, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            n[0] += 1
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    try:
+        yield n
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listen)
+
+
+def test_no_compile_after_warm_up():
+    """Warmed as the benchmark warms an engine (every slot admitted at
+    once, then finishing one per step), the engine compiles nothing more
+    when 0, 1, 2, 3 or all slots free in one step and the number of active
+    rows rises and falls."""
+    from repro import runtime
+    from repro.dist.sharding import use_mesh
+    from repro.launch.mesh import make_host_mesh
+    cfg, _, _, params, _ = _build("llama3.2-3b", dtype=CPU_DTYPE)
+    mesh = make_host_mesh(jax.devices()[:1])
+    max_batch = 8
+    rng = np.random.default_rng(0)
+
+    def prompt(n):
+        return rng.integers(1, cfg.vocab_size, n, dtype=np.int32)
+
+    with use_mesh(mesh):
+        eng = ServeEngine(runtime.compile(cfg, params), max_batch=max_batch,
+                          max_len=64, block_size=16, prefix_reuse=False)
+        buckets = (8, 16, 32)
+        for i in range(max_batch):
+            eng.submit(prompt(buckets[i % 3]), max_new_tokens=3 + i)
+        eng.run()
+        with _compiles() as n:
+            # finish at decode steps 2, 4, 4, 6, 6, 6, 8, 8: 0-3 freed a step
+            for m in (3, 5, 5, 7, 7, 7, 9, 9):
+                eng.submit(prompt(int(rng.integers(5, 33))), max_new_tokens=m)
+            freed = []
+            for step in range(12):
+                if step == 3:              # active rows rise again
+                    for m in (2, 6):
+                        eng.submit(prompt(12), max_new_tokens=m)
+                done = len(eng.finished)
+                eng.step()
+                freed.append(len(eng.finished) - done)
+            eng.run()
+            # every slot frees on the same step
+            for _ in range(max_batch):
+                eng.submit(prompt(20), max_new_tokens=4)
+            eng.run()
+        assert {0, 1, 2, 3} <= set(freed)
+        assert n[0] == 0

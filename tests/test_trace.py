@@ -127,7 +127,7 @@ SPAN_ARGS = {"serve.step": {"queued", "active"}, "serve.admit": set(),
              "serve.prefill": {"uid", "rows", "real", "reused"},
              "serve.install": {"uid", "slot"}, "serve.decode_cache": set(),
              "serve.decode": {"step", "active"}, "serve.writeback": set(),
-             "serve.sample": set(), "serve.zero_slot": {"slot"}}
+             "serve.sample": set(), "serve.zero_slot": {"rows"}}
 
 
 def test_every_span_nests_in_a_step_and_carries_its_args(traced):
@@ -144,8 +144,9 @@ def test_every_span_nests_in_a_step_and_carries_its_args(traced):
     assert {args["uid"] for name, _, _, args in spans
             if name == "serve.install"} <= uids
     assert len(steps) == counters["decode_steps"]
-    assert len([s for s in spans if s[0] == "serve.zero_slot"]) == \
-        counters["slot_resets"]
+    resets = [args for name, _, _, args in spans if name == "serve.zero_slot"]
+    assert len(resets) == counters["reset_calls"]
+    assert sum(a["rows"] for a in resets) == counters["slot_resets"]
     for r in reqs:
         assert r.submit_t <= r.prefill_t <= r.first_token_t
 
@@ -203,8 +204,8 @@ def test_compiled_steps_carry_the_scopes(lowered, kind, scope):
 
 
 def test_kv_programs_carry_their_scopes_and_names(traced):
-    """Each KV program runs under its scope and compiles as a module of the
-    same name, which is how a trace names its runs."""
+    """Each KV program, and the sampler, runs under its scope and compiles
+    as a module of the same name, which is how a trace names its runs."""
     engine = traced[0]
     kv = engine.kv
     table = np.zeros((MAX_BATCH, kv.pages_per_slot), np.int32)
@@ -214,6 +215,12 @@ def test_kv_programs_carry_their_scopes_and_names(traced):
             kv.pools, engine.state_cache, jnp.asarray(table)),
         "kv.slot_scatter": engine._scatter_jit.lower(
             engine.state_cache, kv.make_state(1), 0),
+        "kv.slot_reset": engine._reset_jit.lower(
+            engine.state_cache, kv.make_state(1),
+            np.ones((MAX_BATCH,), bool)),
+        "sample": engine._sample_jit.lower(
+            jnp.zeros((MAX_BATCH, engine.cfg.vocab_size)),
+            np.zeros((MAX_BATCH,), np.float32), jax.random.PRNGKey(0)),
         "kv.zero_pages": kv._zero_jit.lower(kv.pools,
                                             jnp.zeros((1,), jnp.int32)),
         "kv.copy_page": kv._copy_jit.lower(kv.pools, page, page)}
